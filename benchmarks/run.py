@@ -38,6 +38,9 @@ def main() -> None:
     selected = set(args.only.split(",")) if args.only else None
 
     import importlib
+
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for fid, module_name in FIGS:

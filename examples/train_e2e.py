@@ -23,6 +23,7 @@ from repro.core import MemoryObjectStore
 from repro.core.dac import DACPolicy
 from repro.data import PipelineConfig, PreprocessConfig, PreprocessWorker
 from repro.dataplane import Topology
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import ModelConfig, init_params, param_specs
 from repro.run import TrainSession
 from repro.train.optimizer import OptimizerConfig, init_opt_state
@@ -69,6 +70,7 @@ def main():
                     help="resume on this DP degree (elastic factor resize; "
                          "default: same topology)")
     args = ap.parse_args()
+    use_compile_cache()
     prof = PROFILES[args.profile]
     dp = 2
 

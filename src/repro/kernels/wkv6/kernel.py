@@ -26,6 +26,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _dot(a, b, ca: int, cb: int):
+    """f32 matmul contracting ``a``'s dim ``ca`` with ``b``'s dim ``cb``, at
+    full f32 precision: Mosaic's default rounds f32 operands to bf16, which
+    the recurrence state accumulates chunk after chunk."""
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_sc, *,
                 chunk: int, nc: int):
     ic = pl.program_id(2)
@@ -38,32 +47,33 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref, s_sc, *,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)             # (dh,)
+    u = u_ref[0].astype(jnp.float32)             # (1, dh)
 
     lw = jnp.log(jnp.maximum(w, 1e-12))
-    cw = jnp.cumsum(lw, axis=0)                  # inclusive (C, dh)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (Mosaic has no cumsum)
+    cw = _dot((row >= col).astype(jnp.float32), lw, 1, 0)  # (C, dh)
     ecw = cw - lw                                # exclusive
 
-    # pairwise decay, strictly lower triangular (s < t); exponents <= 0
+    # pairwise decay for s < t (exponents <= 0; the clamp keeps the masked
+    # upper triangle finite), strictly lower triangular after the sum
     diff = ecw[:, None, :] - cw[None, :, :]      # (C, C, dh)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) > \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    dec = jnp.where(tri[:, :, None], jnp.exp(diff), 0.0)
+    dec = jnp.exp(jnp.minimum(diff, 0.0))
     scores = jnp.sum(r[:, None, :] * k[None, :, :] * dec, axis=-1)  # (C, C)
-    diag = jnp.sum(r * k * u[None, :], axis=-1)                     # (C,)
-    y = jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    scores = jnp.where(row > col, scores, 0.0)
+    diag = jnp.sum(r * k * u, axis=-1)                              # (C,)
+    y = _dot(scores, v, 1, 0)
     y = y + diag[:, None] * v
     # inter-chunk
     rdec = r * jnp.exp(ecw)
-    y = y + jax.lax.dot_general(rdec, s_sc[...], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    y = y + _dot(rdec, s_sc[...], 1, 0)
     y_ref[0, 0] = y.astype(y_ref.dtype)
     # state update
     total = cw[-1:, :]                           # (1, dh)
     kdec = k * jnp.exp(total - cw)               # (C, dh)
-    s_sc[...] = jnp.exp(total[0])[:, None] * s_sc[...] + jax.lax.dot_general(
-        kdec, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    s_sc[...] = jnp.exp(total[0])[:, None] * s_sc[...] + _dot(kdec, v, 0, 0)
 
     @pl.when(ic == nc - 1)
     def _emit_state():
@@ -95,7 +105,7 @@ def wkv6_fwd(r, k, v, w, u, chunk: int = 32, interpret: bool = True):
             pl.BlockSpec((1, 1, chunk, dh), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, dh), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, dh), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1, dh), lambda b, h, ic: (h, 0)),
+            pl.BlockSpec((1, 1, dh), lambda b, h, ic: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, chunk, dh), lambda b, h, ic: (b, h, ic, 0)),
@@ -107,6 +117,7 @@ def wkv6_fwd(r, k, v, w, u, chunk: int = 32, interpret: bool = True):
         ],
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
         interpret=interpret,
-    )(rk, kk, vk, wk, u)
+    )(rk, kk, vk, wk, u.reshape(H, 1, dh))   # block (1, 1, dh): last two
+                                             # dims equal the array's
     y = jnp.transpose(y, (0, 2, 1, 3))[:, :S]
     return y, state

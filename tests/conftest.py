@@ -1,4 +1,7 @@
+import importlib.util
 import os
+import sys
+from pathlib import Path
 
 # Smoke tests and benches must see the real (single) CPU device — the 512-way
 # host-device override belongs ONLY to repro.launch.dryrun.
@@ -14,6 +17,17 @@ except ImportError:
 import pytest
 
 from repro.core import MemoryObjectStore, Namespace
+
+
+@pytest.fixture(scope="session")
+def chip_smoke():
+    """The repository-root ``chip_smoke.py`` script, loaded by path."""
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules["chip_smoke"] = module   # dataclasses look their module up
+    spec.loader.exec_module(module)
+    return module
 
 
 @pytest.fixture
