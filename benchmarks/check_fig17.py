@@ -8,10 +8,7 @@ Checks (from the fig17 acceptance criteria):
     (best arm vs best arm at depth >= 2 — single-depth pairings are CPU
     scheduling noise at these step sizes);
   * the staging ring actually earns its keep: tgb depth 2 clearly beats the
-    synchronous depth-0 arm, and depth 0 shows the stall the ring hides;
-  * the roofline cross-check holds: compute_vs_roofline is flat across
-    backends (else a tokens/s gap might be a kernel regression, not a
-    data-plane one, and the attribution is lying).
+    synchronous depth-0 arm, and depth 0 shows the stall the ring hides.
 """
 from __future__ import annotations
 
@@ -87,17 +84,6 @@ def main() -> int:
             f"does not exceed d2's "
             f"{tgb_d2.get('data_wait_frac', 0):.3f} by 0.1 "
             f"(attribution no longer sees the stall the ring hides)")
-
-    # roofline cross-check: compute is the same workload in every arm
-    ratios = [r.get("compute_vs_roofline", 0.0) for r in rows.values()
-              if r.get("compute_vs_roofline", 0.0) > 0]
-    if not ratios:
-        failures.append("no compute_vs_roofline columns (cross-check gone)")
-    elif max(ratios) > 2.5 * min(ratios):
-        failures.append(
-            f"compute_vs_roofline spread {min(ratios):.0f}..{max(ratios):.0f}"
-            f" exceeds 2.5x: compute is not flat across arms, so tokens/s "
-            f"gaps are not attributable to the data plane")
 
     if failures:
         print("check_fig17: fused train loop regressed:", file=sys.stderr)

@@ -18,9 +18,7 @@ depths {0, 2, 4}:
 every step); ``depth>=2`` overlaps fetch/pack/h2d of batch N+1 with the
 step on batch N. Derived columns per arm: ``tokens_per_s`` plus the
 stall-attribution split (data_wait/h2d/compute fractions of step wall
-clock) and ``compute_vs_roofline`` (measured compute over the
-``launch/roofline.py`` ideal — flat across arms by construction, which is
-what makes a tokens/s gap attributable to the data plane).
+clock).
 
 ``us_per_call`` is mean step wall-clock µs. ``check_fig17.py`` gates: tgb
 at depth >= 2 stays within 10% of colocated tokens/s with data-wait
@@ -36,7 +34,6 @@ from benchmarks.common import Row, bench_broker, bench_store
 from repro.configs.registry import get_smoke_config
 from repro.data.colocated import ColocatedConfig
 from repro.dataplane import Topology, open_dataplane
-from repro.launch.roofline import ideal_step_s
 from repro.models import init_params, param_specs
 from repro.train.optimizer import OptimizerConfig, init_opt_state
 from repro.train.pipeline import (FusedTrainLoop, FusedReport,
@@ -108,7 +105,6 @@ class _Arms:
             MODEL, OptimizerConfig(), StepConfig()))
         self.params = init_params(param_specs(MODEL), seed=0)
         self.opt = init_opt_state(self.params)
-        self.roofline_s = ideal_step_s(MODEL.param_count(), GB * SEQ)
 
     def drive(self, source, depth: int, steps: int) -> FusedReport:
         loop = FusedTrainLoop(source, self.step_fn, self.params, self.opt,
@@ -197,7 +193,7 @@ def run(quick: bool = True,
                 if w is not None:
                     w.__exit__(None, None, None)
             reports[(backend, depth)] = rep
-            attr = rep.attribution(arms.roofline_s)
+            attr = rep.attribution()
             # median step wall, not mean: a single scheduler straggler in a
             # 10-step window would otherwise dominate the arm comparison
             med_step_s = float(np.median([t.wall_s for t in rep.timings]))
@@ -208,7 +204,6 @@ def run(quick: bool = True,
                 f"h2d_frac={attr['h2d']:.3f};"
                 f"compute_frac={attr['compute']:.3f};"
                 f"bound={attr['bound']};"
-                f"compute_vs_roofline={attr['compute_vs_roofline']:.0f};"
                 f"steps={steps}"))
     rows.sort(key=lambda r: r.name)
     return rows

@@ -65,19 +65,6 @@ def chips(rec: dict) -> int:
     return 512 if rec["mesh"] == "2x16x16" else 256
 
 
-def ideal_step_s(n_params: float, tokens: int, kind: str = "train",
-                 n_chips: int = 1, peak_flops: float = PEAK_FLOPS) -> float:
-    """Roofline-ideal step seconds: MODEL_FLOPS / aggregate peak.
-
-    The fused-train loop (``train/pipeline.py``, fig17) divides measured
-    compute time by this to place each run on the roofline: compute drifting
-    away from the ideal is a kernel/model regression, while data-wait growing
-    under flat compute-vs-roofline indicts the data plane.
-    """
-    factor = 6.0 if kind == "train" else 2.0
-    return factor * n_params * tokens / (n_chips * peak_flops)
-
-
 def analyze_record(rec: dict) -> RooflineRow:
     row = RooflineRow(arch=rec["arch"], shape=rec["shape"], mesh=rec["mesh"],
                       status=rec["status"], reason=rec.get("reason", ""))

@@ -61,6 +61,8 @@ class TrainStats(StatsView):
         "checkpoints": COUNTER,        # committed RunManifest entries
         "last_checkpoint_step": GAUGE,  # logical step the last entry bound
         "reclaim_cycles": COUNTER,
+        "checkpoint_bytes": COUNTER,   # bytes PUT by model uploads
+        "checkpoint_puts": COUNTER,    # PUTs of model uploads (leaves+MANIFEST)
     }
 
 
@@ -170,6 +172,10 @@ class TrainSession:
         naming them. Per-rank watermarks are refreshed only *after* the
         commit, so reclamation can never pass an aligned checkpoint that a
         restart might still need.
+
+        Its spans tile the save: ``checkpoint.claim``, ``checkpoint.upload``
+        (per leaf ``checkpoint.to_host`` and ``checkpoint.put``),
+        ``checkpoint.commit`` and ``checkpoint.watermarks``.
         """
         if not self._readers:
             raise RuntimeError(
@@ -191,20 +197,22 @@ class TrainSession:
         data_step = floor_to_data_step(step, self.topology.dp, data_dp)
         tag = None
         attempt = 0
-        while True:
-            dirname = f"{data_step:010d}" + (f"-{tag}" if tag else "")
-            mkey_candidate = self.ns.key("checkpoints", dirname,
-                                         "MANIFEST.ckpt")
-            claim_key = self.ns.key("checkpoints", dirname, "CLAIM")
-            if not self.store.exists(mkey_candidate) and \
-                    self.store.put_if_absent(claim_key, b"claimed"):
-                break
-            attempt += 1
-            tag = f"r{attempt}"
+        with trace_span("checkpoint.claim", cat="checkpoint", step=step):
+            while True:
+                dirname = f"{data_step:010d}" + (f"-{tag}" if tag else "")
+                mkey_candidate = self.ns.key("checkpoints", dirname,
+                                             "MANIFEST.ckpt")
+                claim_key = self.ns.key("checkpoints", dirname, "CLAIM")
+                if not self.store.exists(mkey_candidate) and \
+                        self.store.put_if_absent(claim_key, b"claimed"):
+                    break
+                attempt += 1
+                tag = f"r{attempt}"
         with trace_span("checkpoint.upload", cat="checkpoint", step=step):
             model_key = upload_model_state(
                 self.ns, data_step, state,
-                cursor=(data_ck.version, data_ck.step), tag=tag)
+                cursor=(data_ck.version, data_ck.step), tag=tag,
+                stats=self.stats)
         with trace_span("checkpoint.commit", cat="checkpoint", step=step):
             entry = self.runs.append(
                 step=step, model_key=model_key, data_token=data_ck.encode(),
@@ -215,11 +223,14 @@ class TrainSession:
                 streams=self.streams_config, mix_seed=self.mix_seed)
         self.stats.checkpoints += 1
         self.stats.last_checkpoint_step = step
-        for r, ck in zip(self._readers, cks):
-            # watermark identity is the mesh position, not discovery order —
-            # a subset of ranks must never shadow another rank's file
-            rank = r.dp_rank * self.topology.cp + r.cp_rank
-            self.data.save_watermark(rank, ck)
+        with trace_span("checkpoint.watermarks", cat="checkpoint",
+                        step=step):
+            for r, ck in zip(self._readers, cks):
+                # watermark identity is the mesh position, not discovery
+                # order — a subset of ranks must never shadow another
+                # rank's file
+                rank = r.dp_rank * self.topology.cp + r.cp_rank
+                self.data.save_watermark(rank, ck)
         self._entry = entry
         return entry
 

@@ -41,6 +41,7 @@ except Exception:  # pragma: no cover - exercised in jax-free CI jobs
     jax = None
 
 from repro.core.objectstore import Namespace, NoSuchKey
+from repro.obs.tracer import trace_span
 
 #: model-checkpoint MANIFEST schema tag (independent of the RunManifest's)
 CKPT_SCHEMA = 2
@@ -118,7 +119,7 @@ def checkpoint_dir_step(dirname: str) -> Optional[int]:
 
 def upload_model_state(ns: Namespace, step: int, state: Dict[str, Any],
                        cursor: Optional[Tuple[int, int]] = None,
-                       tag: Optional[str] = None) -> str:
+                       tag: Optional[str] = None, stats=None) -> str:
     """Upload ``state`` (arbitrary pytree of arrays) under the step's
     checkpoint prefix; returns the ``MANIFEST.ckpt`` key.
 
@@ -127,15 +128,31 @@ def upload_model_state(ns: Namespace, step: int, state: Dict[str, Any],
     for the legacy two-file flow and for human inspection. ``tag`` suffixes
     the directory name (``{step:010d}-{tag}``) so distinct upload attempts
     at the same step never overwrite an object an earlier RunManifest entry
-    already binds.
+    already binds. ``stats`` (a ``TrainStats``), where given, counts the
+    bytes and PUTs of the upload in ``checkpoint_bytes`` and
+    ``checkpoint_puts``.
+
+    Each leaf is a ``checkpoint.to_host`` span (the device-to-host copy and
+    its bytes) then a ``checkpoint.put`` span; the MANIFEST's PUT is one
+    more ``checkpoint.put``.
     """
     dirname = f"{step:010d}" + (f"-{tag}" if tag else "")
     leaves = _leaf_paths(state)
     index = []
+
+    def put(key: str, data: bytes, **args) -> None:
+        with trace_span("checkpoint.put", cat="checkpoint", **args):
+            ns.store.put(key, data)
+        if stats is not None:
+            stats.checkpoint_bytes += len(data)
+            stats.checkpoint_puts += 1
+
     for i, (path, leaf) in enumerate(leaves):
-        arr = np.asarray(leaf)
+        with trace_span("checkpoint.to_host", cat="checkpoint", leaf=i):
+            arr = np.asarray(leaf)
+            data = arr.tobytes()
         key = ns.key("checkpoints", dirname, f"leaf-{i:05d}.npy")
-        ns.store.put(key, arr.tobytes())
+        put(key, data, leaf=i)
         # str(dtype) round-trips extended dtypes (bfloat16 via ml_dtypes)
         index.append({"path": path, "shape": list(arr.shape),
                       "dtype": str(arr.dtype), "key": key})
@@ -147,7 +164,7 @@ def upload_model_state(ns: Namespace, step: int, state: Dict[str, Any],
         "leaves": index,
     }, use_bin_type=True)
     mkey = ns.key("checkpoints", dirname, "MANIFEST.ckpt")
-    ns.store.put(mkey, manifest)  # manifest-last: atomic visibility
+    put(mkey, manifest)  # manifest-last: atomic visibility
     return mkey
 
 

@@ -25,12 +25,11 @@ Structure (one trainer process)::
     ``Batch.tokens`` fan-in there for the same reason.
   * **stall attribution** — every step records data-wait / h2d / compute
     through ``repro.obs`` spans (``pipeline.data_wait``, ``pipeline.h2d``,
-    ``pipeline.compute``; the overlapped staging work is ``pipeline.stage.*``
-    so it never double-counts against the critical path), and
-    ``FusedReport.attribution`` cross-checks measured compute against the
-    ``launch/roofline.py`` ideal: compute drifting off the roofline is a
-    kernel regression, data-wait growing under flat compute is a data-plane
-    regression.
+    ``pipeline.compute``, which holds ``pipeline.dispatch`` — the step
+    enqueued — and ``pipeline.sync`` — the wait for its loss; the
+    overlapped staging work is ``pipeline.stage.*`` so it never
+    double-counts against the critical path). Every span of one step,
+    the staging thread's included, carries that step's ``step`` argument.
 
 Checkpointing: the ring intentionally runs reader cursors *ahead* of the
 trainer. ``aligned_checkpoint`` parks the staging thread, rewinds the source
@@ -325,30 +324,18 @@ class FusedReport:
     def data_wait_frac(self) -> float:
         return self.stall_fractions()["data_wait"]
 
-    def attribution(self, roofline_step_s: Optional[float] = None
-                    ) -> Dict[str, object]:
-        """Where did the time go, and whose fault is a regression?
-
-        With ``roofline_step_s`` (see ``launch.roofline.ideal_step_s``) the
-        report carries ``compute_vs_roofline`` — measured compute per step
-        over the roofline ideal (1/MFU-shaped). Rising compute_vs_roofline
-        at flat data_wait is a kernel problem; rising data_wait at flat
-        compute_vs_roofline is a data-plane problem.
-        """
+    def attribution(self) -> Dict[str, object]:
+        """Where did the time go: the stall fractions, each phase per step,
+        and whether the data plane or the step bounds the loop."""
         fr = self.stall_fractions()
         per_step = {k: v / max(self.steps, 1)
                     for k, v in self.totals().items()}
-        out: Dict[str, object] = {
+        return {
             **fr,
             "per_step": per_step,
             "bound": "data-plane"
             if fr["data_wait"] + fr["h2d"] > fr["compute"] else "compute",
         }
-        if roofline_step_s:
-            out["roofline_step_s"] = roofline_step_s
-            out["compute_vs_roofline"] = \
-                per_step["compute_s"] / roofline_step_s
-        return out
 
 
 # ---------------------------------------------------------------------------
@@ -405,6 +392,7 @@ class FusedTrainLoop:
         self._stop = False
         self._pause = False
         self._idle = threading.Event()   # staging thread parked (not fetching)
+        self._stage_step = 0   # the step the next staged batch is for
         self._error: Optional[BaseException] = None
 
     # -- lifecycle ----------------------------------------------------------
@@ -419,6 +407,7 @@ class FusedTrainLoop:
         self._pause = False
         self._error = None
         self._idle.clear()
+        self._stage_step = self.consumed + len(self._ring)
         self._thread = threading.Thread(target=self._stage_loop, daemon=True,
                                         name="fused-staging")
         self._thread.start()
@@ -470,15 +459,17 @@ class FusedTrainLoop:
                     self._idle.set()
                     return
                 self._idle.clear()
+                step = self._stage_step
             try:
                 cursors = self.source.cursors()
                 t0 = time.perf_counter()
-                with trace_span("pipeline.stage.fetch", cat="prefetch"):
+                with trace_span("pipeline.stage.fetch", cat="prefetch",
+                                step=step):
                     tokens = self.source.next_tokens(
                         timeout_s=self._STAGE_POLL_S)
                 fetch_s = time.perf_counter() - t0
                 t1 = time.perf_counter()
-                with trace_span("pipeline.stage.h2d", cat="h2d"):
+                with trace_span("pipeline.stage.h2d", cat="h2d", step=step):
                     dev = jax.device_put(tokens)
                     jax.block_until_ready(dev)
                 h2d_s = time.perf_counter() - t1
@@ -493,6 +484,7 @@ class FusedTrainLoop:
             with self._cond:
                 self._ring.append(_Staged(dev, tokens, cursors,
                                           fetch_s, h2d_s))
+                self._stage_step = step + 1
                 self.stats.staged_batches += 1
                 self.stats.ring_depth = float(len(self._ring))
                 self._cond.notify_all()
@@ -557,13 +549,16 @@ class FusedTrainLoop:
         for _ in range(num_steps):
             t0 = time.perf_counter()
             entry, data_wait_s, h2d_s = self._acquire()
-            with trace_span("pipeline.compute", cat="compute",
-                            step=self.consumed):
+            step = self.consumed
+            with trace_span("pipeline.compute", cat="compute", step=step):
                 tc = time.perf_counter()
-                self.params, self.opt_state, metrics = self.step_fn(
-                    self.params, self.opt_state,
-                    {"tokens": entry.device_tokens})
-                loss = float(metrics["loss"])   # forces device sync
+                with trace_span("pipeline.dispatch", cat="compute",
+                                step=step):
+                    self.params, self.opt_state, metrics = self.step_fn(
+                        self.params, self.opt_state,
+                        {"tokens": entry.device_tokens})
+                with trace_span("pipeline.sync", cat="compute", step=step):
+                    loss = float(metrics["loss"])   # forces device sync
                 compute_s = time.perf_counter() - tc
             if on_batch is not None:
                 on_batch(self.consumed, entry.host_tokens)
@@ -621,6 +616,7 @@ class FusedTrainLoop:
                     "source is not cursor-restorable: a staged ring cannot "
                     "be aligned for checkpointing (use ReaderFanInSource)")
             self.source.restore(cursors)
+            self._stage_step = self.consumed
             self.stats.align_rewinds += 1
 
     def resume_staging(self) -> None:

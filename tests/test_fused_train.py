@@ -195,6 +195,43 @@ def test_stall_spans_sum_to_wall_clock(tiny_step):
     assert sum(fr.values()) == pytest.approx(1.0, abs=1e-6)
 
 
+def test_each_step_splits_into_dispatch_and_sync(tiny_step):
+    cfg, step_fn, params, opt = tiny_step
+    sess = TrainSession(MemoryObjectStore(), TOPO, namespace="runs/fused_split")
+    _produce(sess, 10, cfg.vocab_size)
+    tracer = enable_tracing()
+    tracer.clear()
+    try:
+        with FusedTrainLoop(_fan_in(sess), step_fn, params, opt,
+                            topology=TOPO, depth=2, timeout_s=30.0) as loop:
+            loop.run(2)
+            loop.aligned_checkpoint(sess, {"params": loop.params})
+            loop.run(2)
+    finally:
+        disable_tracing()
+    sess.close()
+    spans = tracer.spans()
+    tracer.clear()
+
+    # one dispatch and one sync per step, both children of its compute
+    computes = {s.args["step"]: s for s in spans
+                if s.name == "pipeline.compute"}
+    assert sorted(computes) == [0, 1, 2, 3]
+    for name in ("pipeline.dispatch", "pipeline.sync"):
+        parts = [s for s in spans if s.name == name]
+        assert sorted(s.args["step"] for s in parts) == [0, 1, 2, 3]
+        assert all(s.parent == computes[s.args["step"]].id for s in parts)
+    # the staging thread's spans carry the step they stage for, counted
+    # again from the consumed frontier after the alignment's rewind
+    align = next(s for s in spans if s.name == "pipeline.align")
+    staged = sorted((s.t0, s.args["step"]) for s in spans
+                    if s.name == "pipeline.stage.h2d")
+    before = [k for t, k in staged if t < align.t0]
+    after = [k for t, k in staged if t > align.t0]
+    assert before == list(range(len(before))) and len(before) >= 2
+    assert after[:2] == [2, 3]
+
+
 def test_throttled_store_shifts_split_toward_data_wait(tiny_step):
     cfg, step_fn, params, opt = tiny_step
 

@@ -227,6 +227,71 @@ def test_tracer_records_spans_that_raise():
     TRACER.clear()
 
 
+def test_spans_join_the_profiler_trace_on_its_clock(tmp_path):
+    jax = pytest.importorskip("jax")
+    from jax.profiler import ProfileData
+    disable_tracing()
+    TRACER.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        with trace_span("obs.untraced", cat="read"):
+            pass
+        enable_tracing()
+        try:
+            with trace_span("obs.outer", cat="read", step=3):
+                with trace_span("obs.inner", cat="read"):
+                    time.sleep(0.002)
+        finally:
+            disable_tracing()
+    spans = {s.name: s for s in TRACER.spans()}
+    TRACER.clear()
+    assert set(spans) == {"obs.outer", "obs.inner"}
+    outer, inner = spans["obs.outer"], spans["obs.inner"]
+    assert outer.parent is None and inner.parent == outer.id != inner.id
+
+    data = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    env = next(p for p in data.planes if p.name == "Task Environment")
+    base = dict(env.stats)["profile_start_time"]   # ns, realtime clock
+    starts = {ev.name: base + ev.start_ns
+              for plane in data.planes if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events}
+    assert "obs.untraced" not in starts   # no mirror with the tracer off
+    for s in (outer, inner):
+        assert abs(starts[s.name] - s.t0 * 1e9) < 200e3, s.name
+
+
+def test_stall_report_counts_nested_spans_once():
+    enable_tracing()
+    TRACER.clear()
+    try:
+        with trace_span("pipeline.compute", cat="compute", step=0):
+            with trace_span("pipeline.dispatch", cat="compute", step=0):
+                time.sleep(0.01)
+            with trace_span("pipeline.sync", cat="compute", step=0):
+                time.sleep(0.01)
+        with trace_span("pipeline.data_wait", cat="read", step=1):
+            with trace_span("consumer.wait", cat="read", step=1):
+                time.sleep(0.01)
+        # a wait on the staging thread's fetch is off the critical path
+        with trace_span("pipeline.stage.fetch", cat="prefetch", step=2):
+            with trace_span("consumer.wait", cat="read", step=2):
+                time.sleep(0.01)
+    finally:
+        disable_tracing()
+    spans = {s.name: s for s in TRACER.spans()}
+    report = TRACER.stall_report()
+    TRACER.clear()
+    compute_ms = spans["pipeline.compute"].dur * 1e3
+    wait_ms = spans["pipeline.data_wait"].dur * 1e3
+    line = next(ln for ln in report.splitlines()
+                if ln.startswith("data-plane wait"))
+    words = line.split()
+    assert float(words[2]) == pytest.approx(wait_ms, abs=0.011)
+    assert float(words[6]) == pytest.approx(compute_ms, abs=0.011)
+    cat = next(ln for ln in report.splitlines()
+               if ln.startswith("category compute"))
+    assert float(cat.split()[2]) == pytest.approx(compute_ms, abs=0.011)
+
+
 # ---------------------------------------------------------------------------
 # flight recorder
 # ---------------------------------------------------------------------------

@@ -5,6 +5,8 @@ claims), the TrainSession save/resume round trip, exactly-once recovery from
 a kill between model upload and RunManifest commit, RunManifest-bounded
 reclamation, and the fsck audits of the aligned chain.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -12,6 +14,7 @@ from repro.core import (InjectedCrash, FaultInjector, MemoryObjectStore,
                         Namespace, Reclaimer, Watermark, read_trim_marker,
                         write_watermark)
 from repro.dataplane import Checkpoint, Topology
+from repro.obs.tracer import TRACER, disable_tracing, enable_tracing
 from repro.ops import fsck
 from repro.run import (RunManifest, RunManifestError, RunManifestStore,
                        TrainSession)
@@ -116,6 +119,55 @@ def test_train_session_round_trip_exactly_once():
                           np.arange(5, dtype=np.float32))
     r2 = [resumed.reader(dp_rank=d) for d in range(2)]
     assert _drain(r2, 6) == tail  # byte-identical replay: exactly-once
+
+
+class _SlowPuts(MemoryObjectStore):
+    """A store whose PUTs take 2 ms, so a save lasts long enough to time."""
+
+    def put(self, key, data):
+        time.sleep(0.002)
+        super().put(key, data)
+
+
+def test_checkpoint_spans_tile_the_save_and_count_its_bytes():
+    store = _SlowPuts()
+    sess = TrainSession(store, Topology(dp=2, cp=1), namespace=NS)
+    _fill(sess, 4)
+    readers = [sess.reader(dp_rank=d) for d in range(2)]
+    _drain(readers, 2)
+    leaves = [np.arange(6, dtype=np.float32), np.ones((3, 4), np.int32),
+              np.zeros(2, np.float64)]
+    state = {"a": leaves[0], "b": {"c": leaves[1]}, "d": leaves[2]}
+    enable_tracing()
+    TRACER.clear()
+    try:
+        t0 = time.perf_counter()
+        entry = sess.checkpoint(state)
+        wall = time.perf_counter() - t0
+    finally:
+        disable_tracing()
+    spans = TRACER.spans()
+    TRACER.clear()
+
+    upload = [s for s in spans if s.name == "checkpoint.upload"]
+    assert len(upload) == 1
+    to_host = [s for s in spans if s.name == "checkpoint.to_host"]
+    puts = [s for s in spans if s.name == "checkpoint.put"]
+    assert len(to_host) == len(leaves)
+    assert len(puts) == len(leaves) + 1          # the leaves, then MANIFEST
+    assert all(s.parent == upload[0].id for s in to_host + puts)
+    manifest = store.get(entry.model_key)
+    assert sess.stats.checkpoint_bytes == \
+        sum(a.nbytes for a in leaves) + len(manifest)
+    assert sess.stats.checkpoint_puts == len(leaves) + 1
+    top = [s for s in spans if s.parent is None and s.cat == "checkpoint"]
+    assert [s.name for s in top] == ["checkpoint.claim", "checkpoint.upload",
+                                     "checkpoint.commit",
+                                     "checkpoint.watermarks"]
+    assert all(s.args["step"] == 2 for s in top)
+    assert sum(s.dur for s in top) == pytest.approx(wall, rel=0.10)
+    assert sum(s.dur for s in to_host + puts) == \
+        pytest.approx(upload[0].dur, rel=0.10)
 
 
 def test_train_session_checkpoint_requires_readers_and_lockstep():
