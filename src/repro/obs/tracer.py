@@ -19,7 +19,9 @@ While enabled, each span
     without jax has no device trace to join);
   * records its ``id`` and the ``parent`` id of the innermost span open on
     the same thread, so a span's self time (its duration less its
-    children's) can be computed.
+    children's) can be computed. Work handed to another thread names its
+    parent explicitly: ``span(..., parent=TRACER.current())`` taken on the
+    thread that hands it over.
 
 Two export surfaces:
 
@@ -71,7 +73,8 @@ STAGING_CAT = "prefetch"
 class Span:
     """One completed timed region: ``t0`` in seconds since the epoch on the
     profiler's host clock, ``dur`` in seconds, ``id`` unique in the process,
-    ``parent`` the id of the span it ran in on the same thread, or None."""
+    ``parent`` the id of the span it ran in (on the same thread, or the one
+    named when it was opened), or None."""
 
     __slots__ = ("name", "cat", "t0", "dur", "tid", "args", "id", "parent")
 
@@ -131,15 +134,17 @@ class _LiveSpan:
                  "_mirror", "id", "parent")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[dict]):
+                 args: Optional[dict], parent: Optional[int] = None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self.parent = parent
 
     def __enter__(self):
         stack = self._stack = self._tracer._open.stack
-        self.parent = stack[-1] if stack else None
+        if self.parent is None and stack:
+            self.parent = stack[-1]
         self.id = next(self._tracer._ids)
         stack.append(self.id)
         self._mirror = _annotation(self.name, self.args)
@@ -160,10 +165,12 @@ class _LiveSpan:
 
 
 def self_times(spans: List[Span]) -> Dict[int, float]:
-    """Each span's duration less those of its recorded children, by id."""
+    """Each span's duration less those of its recorded children on its own
+    thread, by id (children on other threads may overlap each other)."""
     own = {s.id: s.dur for s in spans}
+    tid = {s.id: s.tid for s in spans}
     for s in spans:
-        if s.parent in own:
+        if s.parent in own and tid[s.parent] == s.tid:
             own[s.parent] -= s.dur
     return own
 
@@ -192,11 +199,19 @@ class Tracer:
         with self._lock:
             self._ring.clear()
 
-    def span(self, name: str, cat: str = "", **args):
-        """Context manager timing one region. Free when disabled."""
+    def span(self, name: str, cat: str = "", *,
+             parent: Optional[int] = None, **args):
+        """Context manager timing one region. Free when disabled. ``parent``
+        names the enclosing span's id where that span is open on another
+        thread; by default it is the innermost span open on this one."""
         if not self.enabled:
             return _NULL_SPAN
-        return _LiveSpan(self, name, cat, args or None)
+        return _LiveSpan(self, name, cat, args or None, parent)
+
+    def current(self) -> Optional[int]:
+        """The id of the innermost span open on this thread, or None."""
+        stack = self._open.stack
+        return stack[-1] if stack else None
 
     def _record(self, span: Span) -> None:
         ident = threading.get_ident()
@@ -316,8 +331,9 @@ def disable_tracing() -> Tracer:
     return TRACER.disable()
 
 
-def trace_span(name: str, cat: str = "", **args):
+def trace_span(name: str, cat: str = "", *, parent: Optional[int] = None,
+               **args):
     """Module-level shortcut: ``with trace_span("commit.cput", cat="commit")``."""
     if not TRACER.enabled:
         return _NULL_SPAN
-    return _LiveSpan(TRACER, name, cat, args or None)
+    return _LiveSpan(TRACER, name, cat, args or None, parent)

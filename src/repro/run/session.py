@@ -63,6 +63,8 @@ class TrainStats(StatsView):
         "reclaim_cycles": COUNTER,
         "checkpoint_bytes": COUNTER,   # bytes PUT by model uploads
         "checkpoint_puts": COUNTER,    # PUTs of model uploads (leaves+MANIFEST)
+        # most PUTs in flight at once in the last save (1: one at a time)
+        "checkpoint_puts_inflight_peak": GAUGE,
     }
 
 
@@ -174,8 +176,10 @@ class TrainSession:
         restart might still need.
 
         Its spans tile the save: ``checkpoint.claim``, ``checkpoint.upload``
-        (per leaf ``checkpoint.to_host`` and ``checkpoint.put``),
-        ``checkpoint.commit`` and ``checkpoint.watermarks``.
+        (per leaf ``checkpoint.to_host``, then ``checkpoint.drain`` and the
+        MANIFEST's ``checkpoint.put``; the leaves' ``checkpoint.put`` may
+        run on pool threads, under the upload), ``checkpoint.commit`` and
+        ``checkpoint.watermarks``.
         """
         if not self._readers:
             raise RuntimeError(
@@ -243,7 +247,10 @@ class TrainSession:
         if not entry.model_key:
             raise NoSuchKey(f"RunManifest seq={entry.seq} carries no model "
                             f"checkpoint")
-        state, _doc = load_model_state(self.ns, entry.model_key, template)
+        with trace_span("checkpoint.restore", cat="checkpoint",
+                        step=entry.step):
+            state, _doc = load_model_state(self.ns, entry.model_key,
+                                           template)
         return state
 
     @property

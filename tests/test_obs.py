@@ -259,6 +259,49 @@ def test_spans_join_the_profiler_trace_on_its_clock(tmp_path):
         assert abs(starts[s.name] - s.t0 * 1e9) < 200e3, s.name
 
 
+def test_span_opened_on_another_thread_names_its_parent():
+    import threading
+
+    from repro.obs.tracer import self_times
+
+    enable_tracing()
+    TRACER.clear()
+    try:
+        with trace_span("checkpoint.upload", cat="checkpoint") as up:
+            parent = TRACER.current()
+
+            def work(leaf):
+                with trace_span("checkpoint.put", cat="checkpoint",
+                                parent=parent, leaf=leaf):
+                    with trace_span("store.io", cat="checkpoint"):
+                        time.sleep(0.01)
+
+            threads = [threading.Thread(target=work, args=(i,))
+                       for i in range(2)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=10)
+            assert not any(t.is_alive() for t in threads)
+        assert TRACER.current() is None
+    finally:
+        disable_tracing()
+    spans = TRACER.spans()
+    TRACER.clear()
+    assert parent == up.id
+    puts = [s for s in spans if s.name == "checkpoint.put"]
+    upload = next(s for s in spans if s.name == "checkpoint.upload")
+    assert len(puts) == 2 and all(s.parent == upload.id for s in puts)
+    assert all(s.tid != upload.tid for s in puts)
+    # a span nested on the worker takes the worker's open span as parent
+    ios = [s for s in spans if s.name == "store.io"]
+    assert sorted(s.parent for s in ios) == sorted(s.id for s in puts)
+    # overlapping children on other threads leave the parent's self time
+    own = self_times(spans)
+    assert own[upload.id] == upload.dur
+    assert all(own[s.id] < s.dur for s in puts)
+
+
 def test_stall_report_counts_nested_spans_once():
     enable_tracing()
     TRACER.clear()

@@ -122,52 +122,232 @@ def test_train_session_round_trip_exactly_once():
 
 
 class _SlowPuts(MemoryObjectStore):
-    """A store whose PUTs take 2 ms, so a save lasts long enough to time."""
+    """A store whose PUTs take ``delay`` seconds (2 ms by default), so a
+    save lasts long enough to time."""
+
+    def __init__(self, delay: float = 0.002, **kw):
+        super().__init__(**kw)
+        self.delay = delay
 
     def put(self, key, data):
-        time.sleep(0.002)
+        time.sleep(self.delay)
         super().put(key, data)
 
 
-def test_checkpoint_spans_tile_the_save_and_count_its_bytes():
-    store = _SlowPuts()
+@pytest.fixture
+def pooled(monkeypatch):
+    """A pool threshold of 16 bytes, so a few-leaf state takes the
+    concurrent path."""
+    from repro.train import checkpoint as ckpt
+
+    monkeypatch.setattr(ckpt, "POOL_MIN_BYTES", 16)
+    return ckpt
+
+
+def _session_at_step(store, steps: int = 2) -> TrainSession:
     sess = TrainSession(store, Topology(dp=2, cp=1), namespace=NS)
-    _fill(sess, 4)
+    _fill(sess, 2 * steps + 4)
     readers = [sess.reader(dp_rank=d) for d in range(2)]
-    _drain(readers, 2)
-    leaves = [np.arange(6, dtype=np.float32), np.ones((3, 4), np.int32),
-              np.zeros(2, np.float64)]
-    state = {"a": leaves[0], "b": {"c": leaves[1]}, "d": leaves[2]}
+    _drain(readers, steps)
+    return sess
+
+
+def _traced(fn):
     enable_tracing()
     TRACER.clear()
     try:
         t0 = time.perf_counter()
-        entry = sess.checkpoint(state)
+        out = fn()
         wall = time.perf_counter() - t0
     finally:
         disable_tracing()
     spans = TRACER.spans()
     TRACER.clear()
+    return out, wall, spans
+
+
+def test_checkpoint_spans_tile_the_save_and_count_its_bytes(pooled):
+    store = _SlowPuts(delay=0.01)
+    sess = _session_at_step(store)
+    leaves = [np.arange(6, dtype=np.float32), np.ones((3, 4), np.int32),
+              np.zeros(2, np.float64)]
+    state = {"a": leaves[0], "b": {"c": leaves[1]}, "d": leaves[2]}
+    sess.checkpoint(state)     # starts the pool's threads, outside the spans
+    bytes0, puts0 = sess.stats.checkpoint_bytes, sess.stats.checkpoint_puts
+    entry, wall, spans = _traced(lambda: sess.checkpoint(state))
 
     upload = [s for s in spans if s.name == "checkpoint.upload"]
     assert len(upload) == 1
+    up = upload[0]
     to_host = [s for s in spans if s.name == "checkpoint.to_host"]
+    drain = [s for s in spans if s.name == "checkpoint.drain"]
     puts = [s for s in spans if s.name == "checkpoint.put"]
-    assert len(to_host) == len(leaves)
-    assert len(puts) == len(leaves) + 1          # the leaves, then MANIFEST
-    assert all(s.parent == upload[0].id for s in to_host + puts)
+    leaf_puts = [s for s in puts if s.args and "leaf" in s.args]
+    manifest_put = [s for s in puts if s not in leaf_puts]
+    assert len(to_host) == len(leaves) and len(drain) == 1
+    assert len(leaf_puts) == len(leaves) and len(manifest_put) == 1
+    # the leaves' PUTs ran on the pool, under the upload
+    assert all(s.parent == up.id for s in to_host + drain + puts)
+    assert all(s.tid != up.tid for s in leaf_puts)
+    # largest leaf first: b/c (48 bytes), a (24), d (16)
+    assert [s.args["leaf"] for s in to_host] == [1, 0, 2]
     manifest = store.get(entry.model_key)
-    assert sess.stats.checkpoint_bytes == \
+    assert sess.stats.checkpoint_bytes - bytes0 == \
         sum(a.nbytes for a in leaves) + len(manifest)
-    assert sess.stats.checkpoint_puts == len(leaves) + 1
+    assert sess.stats.checkpoint_puts - puts0 == len(leaves) + 1
     top = [s for s in spans if s.parent is None and s.cat == "checkpoint"]
     assert [s.name for s in top] == ["checkpoint.claim", "checkpoint.upload",
                                      "checkpoint.commit",
                                      "checkpoint.watermarks"]
     assert all(s.args["step"] == 2 for s in top)
     assert sum(s.dur for s in top) == pytest.approx(wall, rel=0.10)
-    assert sum(s.dur for s in to_host + puts) == \
-        pytest.approx(upload[0].dur, rel=0.10)
+    # the trainer thread's copies, its wait and the MANIFEST tile the upload
+    assert sum(s.dur for s in to_host + drain + manifest_put) == \
+        pytest.approx(up.dur, rel=0.10)
+    assert all(s.tid == up.tid for s in to_host + drain + manifest_put)
+
+
+def test_small_checkpoint_stays_on_the_calling_thread():
+    """Under the pool threshold the save runs one request at a time: each
+    leaf's copy then its PUT, largest leaf first, on the trainer's
+    thread."""
+    sess = _session_at_step(_SlowPuts())
+    state = {"a": np.arange(6, dtype=np.float32), "b": np.ones(9, np.int32)}
+    _entry, _wall, spans = _traced(lambda: sess.checkpoint(state))
+    up = [s for s in spans if s.name == "checkpoint.upload"][0]
+    parts = [(s.name, (s.args or {}).get("leaf")) for s in spans
+             if s.parent == up.id]
+    assert parts == [("checkpoint.to_host", 1), ("checkpoint.put", 1),
+                     ("checkpoint.to_host", 0), ("checkpoint.put", 0),
+                     ("checkpoint.drain", None), ("checkpoint.put", None)]
+    assert all(s.tid == up.tid for s in spans if s.parent == up.id)
+    assert sess.stats.checkpoint_puts_inflight_peak == 1
+
+
+def test_checkpoint_leaf_puts_overlap(pooled):
+    store = _SlowPuts(delay=0.02)
+    sess = _session_at_step(store)
+    state = {f"w{i}": np.full(8, i, np.float32) for i in range(8)}
+    _entry, _wall, spans = _traced(lambda: sess.checkpoint(state))
+    up = [s for s in spans if s.name == "checkpoint.upload"][0]
+    leaf_puts = [s for s in spans if s.name == "checkpoint.put"
+                 and s.args and "leaf" in s.args]
+    assert len(leaf_puts) == 8
+    assert up.dur < 0.5 * sum(s.dur for s in leaf_puts)
+    assert sess.stats.checkpoint_puts_inflight_peak > 1
+    assert sess.stats.checkpoint_puts == 9
+    resumed = TrainSession.resume(store, NS)
+    back = resumed.restore_model({k: np.zeros(8, np.float32) for k in state})
+    for k, v in state.items():
+        assert np.asarray(back[k]).tobytes() == v.tobytes()
+
+
+def test_save_holds_at_most_held_bytes_of_copies(pooled, monkeypatch):
+    """Bytes of PUTs in flight count against the bound too: with room for
+    two 32-byte leaves, no more than two PUTs run at once, and the trainer
+    waits for them in ``checkpoint.drain`` spans."""
+    monkeypatch.setattr(pooled, "HELD_BYTES", 64)
+    store = _SlowPuts(delay=0.02)
+    sess = _session_at_step(store)
+    state = {f"w{i}": np.full(8, i, np.float32) for i in range(6)}
+    _entry, _wall, spans = _traced(lambda: sess.checkpoint(state))
+    assert sess.stats.checkpoint_puts_inflight_peak == 2
+    up = [s for s in spans if s.name == "checkpoint.upload"][0]
+    drain = [s for s in spans if s.name == "checkpoint.drain"]
+    assert len(drain) > 1 and all(s.parent == up.id for s in drain)
+    to_host = [s for s in spans if s.name == "checkpoint.to_host"]
+    manifest_put = [s for s in spans if s.name == "checkpoint.put"
+                    and not (s.args and "leaf" in s.args)]
+    assert sum(s.dur for s in to_host + drain + manifest_put) == \
+        pytest.approx(up.dur, rel=0.10)
+
+
+def test_failed_leaf_put_on_the_pool_leaves_no_manifest(pooled):
+    store = _SlowPuts(delay=0.01, faults=FaultInjector())
+    sess = _session_at_step(store)
+    sess.checkpoint({"w": np.arange(4, dtype=np.float32)})   # aligned @ 2
+    readers = sess._readers
+    _drain(readers, 1)
+    store.faults.crash_on("put", "leaf-", nth=2)
+    state = {f"w{i}": np.full(8, i, np.float32) for i in range(6)}
+    with pytest.raises(InjectedCrash):
+        sess.checkpoint(state)
+    claimed = sess.ns.key("checkpoints", "0000000003")
+    keys = store.list(claimed)
+    time.sleep(0.05)
+    assert store.list(claimed) == keys         # no PUT left in flight
+    store.faults = None
+    assert f"{claimed}/CLAIM" in keys
+    assert not any(k.endswith("MANIFEST.ckpt") for k in keys)
+    assert sess.runs.latest().step == 2        # no entry for the failed save
+    assert sess.stats.checkpoints == 1
+
+    resumed = TrainSession.resume(store, NS)
+    assert resumed.resume_step == 2
+    back = resumed.restore_model({"w": np.zeros(4, np.float32)})
+    assert np.asarray(back["w"]).tobytes() == \
+        np.arange(4, dtype=np.float32).tobytes()
+    report = fsck(Namespace(store, NS))
+    assert any(i.kind == "pending-model-checkpoint" and "0000000003" in i.key
+               for i in report.issues), report.summary()
+
+
+def test_checkpoint_counts_stay_exact_under_thread_churn(pooled,
+                                                         monkeypatch):
+    """More pool threads than cores, a tiny bound on held bytes and a short
+    switch interval: a lost update would break the counts or the bytes."""
+    import os
+    import sys
+
+    from repro.core import IOPool
+
+    pool = IOPool(2 * (os.cpu_count() or 4), name="test-ckpt")
+    monkeypatch.setattr(pooled, "_pool", pool)
+    monkeypatch.setattr(pooled, "HELD_BYTES", 64)
+    store = MemoryObjectStore()
+    sess = _session_at_step(store)
+    rng = np.random.default_rng(5)
+    state = {f"w{i:03d}": rng.standard_normal(int(rng.integers(1, 40)))
+             .astype(np.float32) for i in range(200)}
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        t0 = time.perf_counter()
+        entry = sess.checkpoint(state)
+        assert time.perf_counter() - t0 < 60
+    finally:
+        sys.setswitchinterval(old)
+    manifest = store.get(entry.model_key)
+    assert sess.stats.checkpoint_puts == len(state) + 1
+    assert sess.stats.checkpoint_bytes == \
+        sum(v.nbytes for v in state.values()) + len(manifest)
+    assert 1 <= sess.stats.checkpoint_puts_inflight_peak <= pool.max_workers
+    back = TrainSession.resume(store, NS).restore_model(
+        {k: np.zeros_like(v) for k, v in state.items()})
+    pool.shutdown()
+    assert all(np.asarray(back[k]).tobytes() == v.tobytes()
+               for k, v in state.items())
+
+
+def test_restore_gets_run_under_the_restore_span(pooled):
+    store = MemoryObjectStore()
+    sess = _session_at_step(store)
+    state = {"a": np.arange(40, dtype=np.float32), "b": np.ones(3, np.int8)}
+    sess.checkpoint(state)
+    resumed = TrainSession.resume(store, NS)
+    template = {k: np.zeros_like(v) for k, v in state.items()}
+    back, _wall, spans = _traced(lambda: resumed.restore_model(template))
+    restore = [s for s in spans if s.name == "checkpoint.restore"]
+    assert len(restore) == 1
+    gets = [s for s in spans if s.name == "checkpoint.get"]
+    # the MANIFEST on the calling thread, then one GET per leaf on the pool
+    assert len(gets) == 1 + 2
+    assert all(s.parent == restore[0].id for s in gets)
+    leaf_gets = [s for s in gets if s.args and "leaf" in s.args]
+    assert sorted(s.args["leaf"] for s in leaf_gets) == [0, 1]
+    assert all(s.tid != restore[0].tid for s in leaf_gets)
+    assert all(np.asarray(back[k]).tobytes() == v.tobytes()
+               for k, v in state.items())
 
 
 def test_train_session_checkpoint_requires_readers_and_lockstep():

@@ -119,3 +119,58 @@ def test_checkpoint_restore_specific_step(ns):
     restored, cursor, step = restore_checkpoint(ns, {"x": jnp.float32(0)},
                                                 step=5)
     assert float(restored["x"]) == 5.0 and step == 5
+
+
+#: the concurrent restore's cases, each beside a 160-byte float32 leaf so the
+#: state is over the (64-byte) pool threshold
+_RESTORE_CASES = {
+    "ragged": lambda: jnp.arange(7 * 13, dtype=jnp.float32).reshape(7, 13),
+    "bfloat16": lambda: (jnp.arange(100) / 7).astype(jnp.bfloat16),
+    "scalar": lambda: {"opt": {"step": jnp.int32(7)}},
+    "under_the_threshold": lambda: jnp.ones(3, jnp.float32) / 3,
+}
+
+
+@pytest.fixture
+def pool64(monkeypatch):
+    from repro.train import checkpoint as ckpt
+
+    monkeypatch.setattr(ckpt, "POOL_MIN_BYTES", 64)
+    return ckpt
+
+
+@pytest.mark.parametrize("case", sorted(_RESTORE_CASES))
+def test_concurrent_restore_is_bit_exact(ns, store, pool64, case):
+    state = {"case": _RESTORE_CASES[case](),
+             "pad": jnp.arange(40, dtype=jnp.float32) * 1.1}
+    key = pool64.upload_model_state(ns, 3, state)
+    template = jax.tree_util.tree_map(jnp.zeros_like, state)
+    store.stats.gets = store.stats.range_gets = 0
+    back, doc = pool64.load_model_state(ns, key, template)
+    assert doc["step"] == 3
+    leaves, want = jax.tree_util.tree_leaves(back), \
+        jax.tree_util.tree_leaves(state)
+    for a, b in zip(leaves, want):
+        assert (a.dtype, a.shape) == (b.dtype, b.shape)
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    # the MANIFEST, then one whole GET per leaf
+    assert store.stats.gets == 1 + len(want)
+    assert store.stats.range_gets == 0
+
+
+@pytest.mark.parametrize("nth", [1, 2])
+def test_concurrent_restore_raises_on_a_failed_get(ns, store, pool64, nth):
+    from repro.core import FaultInjector, InjectedCrash
+
+    state = {"big": jnp.arange(100, dtype=jnp.float32),
+             "small": jnp.ones(4, jnp.int32)}
+    key = pool64.upload_model_state(ns, 1, state)
+    store.faults = FaultInjector()
+    store.faults.crash_on("get", "leaf-", nth=nth)
+    template = jax.tree_util.tree_map(jnp.zeros_like, state)
+    with pytest.raises(InjectedCrash):
+        pool64.load_model_state(ns, key, template)
+    store.faults = None
+    back, _doc = pool64.load_model_state(ns, key, template)
+    assert np.asarray(back["big"]).tobytes() == \
+        np.asarray(state["big"]).tobytes()
