@@ -54,10 +54,10 @@ def main(argv=None) -> int:
     bench_run.configure_jax()
     from bench import harness
     from bench.reference import dataplane as ref_data
-    from bench.reference import granite as ref_model
     cell = harness.load_cell(bench_run.CHECKOUT, args.workload)
     device = bench_run.check_device(cell.chips)
     cfg, limits = cell.config, cell.config["limits"]
+    ref_model = harness.reference(cfg)
     controls = {int(s) for s in args.control_seeds.split(",") if s}
     for seed in [int(s) for s in args.seeds.split(",")]:
         t = time.perf_counter()

@@ -14,9 +14,14 @@ commit the benchmark's own grids, and drives it through its first
 reference follows. The same trainer then runs the window. A configuration
 with ``checkpoint_every`` saves first and then trains that many steps, round
 after round, through ``FusedTrainLoop.aligned_checkpoint``.
+
+The model is the configuration's too: its ``"reference"`` key names the
+module of ``bench/reference/`` that gives the program's config, the weights,
+the reference step and the model FLOPs (``reference``).
 """
 from __future__ import annotations
 
+import importlib
 import json
 import threading
 import time
@@ -29,8 +34,8 @@ from typing import Callable, Dict, List, Mapping, Optional, Tuple
 import numpy as np
 
 from bench import store as bench_store
+from bench.reference import CONTRACT
 from bench.reference import dataplane as ref_data
-from bench.reference import granite as ref_model
 
 ROOT = Path(__file__).resolve().parent
 NAMESPACE = "runs/bench"
@@ -123,18 +128,33 @@ class CompileCounter:
 # the program, as a configuration states it
 # ---------------------------------------------------------------------------
 
+def reference(cfg: Mapping):
+    """The module ``bench.reference.<cfg["reference"]>``, imported once
+    (``sys.modules`` keeps it); ``SystemExit`` where the configuration names
+    none, or one that is missing or lacks a name of the contract."""
+    name = cfg.get("reference")
+    where = f"configuration {cfg.get('name')!r}"
+    if not isinstance(name, str) or not name.isidentifier():
+        raise SystemExit(f"bench: {where} names no reference module: give "
+                         f"it a \"reference\" key naming "
+                         f"bench/reference/<name>.py")
+    module = f"bench.reference.{name}"
+    try:
+        mod = importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise SystemExit(f"bench: {where} names the reference {name!r}, "
+                         f"but there is no bench/reference/{name}.py")
+    missing = [n for n in CONTRACT if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"bench: bench/reference/{name}.py, the reference "
+                         f"of {where}, lacks {missing}")
+    return mod
+
+
 def model_config(cfg: Mapping):
-    from repro.models import ModelConfig
-    m, prec = cfg["model"], cfg["precision"]
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        rope_theta=m["rope_theta"], norm_eps=m["rms_norm_eps"],
-        tie_embeddings=m["tie_word_embeddings"],
-        param_dtype=prec["params"], compute_dtype=prec["compute"])
+    return reference(cfg).program_config(cfg)
 
 
 def optimizer_config(cfg: Mapping):
@@ -166,8 +186,9 @@ def make_state(cfg: Mapping, seed: int):
     in one jitted call."""
     import jax
     from repro.train.optimizer import init_opt_state
-    init = jax.jit(ref_model.make_init(cfg["model"]))
-    params = init(ref_model.seed_words(seed))
+    ref = reference(cfg)
+    init = jax.jit(ref.make_init(cfg["model"]))
+    params = init(ref.seed_words(seed))
     dt = cfg["precision"]["optimizer_state"]
     opt = jax.jit(lambda p: init_opt_state(p, dt))(params)
     return params, opt
@@ -176,8 +197,8 @@ def make_state(cfg: Mapping, seed: int):
 def abstract_state(cfg: Mapping):
     import jax
     from repro.train.optimizer import init_opt_state
-    params = jax.eval_shape(ref_model.make_init(cfg["model"]),
-                            ref_model.seed_words(0))
+    ref = reference(cfg)
+    params = jax.eval_shape(ref.make_init(cfg["model"]), ref.seed_words(0))
     dt = cfg["precision"]["optimizer_state"]
     return {"params": params,
             "opt": jax.eval_shape(lambda p: init_opt_state(p, dt), params)}
@@ -285,6 +306,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     from repro.train.pipeline import FusedTrainLoop, ReaderFanInSource
 
     cfg, traffic = cell.config, cell.traffic
+    ref_model = reference(cfg)
     dp_cfg, train = cfg["data_plane"], cfg["train"]
     topo = topology(cfg)
     run = Run(config=cfg, device_kind=jax.devices()[0].device_kind)

@@ -30,6 +30,12 @@ or more dimensions, which takes in the stacked per-layer norm scales.
 reference; ``"fp8"`` is the lower-precision control (operands rounded to
 float8_e4m3fn, cotangents to float8_e5m2, each with a per-tensor scale,
 products accumulated in float32).
+
+Besides the reference, this module states how the benchmark runs the
+program on Granite (``program_config``), what the model's FLOPs are
+(``flops_per_token``, from ``bench/flops.py``) and the CPU tests' size
+(``SMOKE``): the contract of ``bench/reference/__init__.py``. Only
+``program_config`` imports the program, inside the function.
 """
 from __future__ import annotations
 
@@ -40,9 +46,37 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.flops import flops_per_token  # noqa: F401  (the contract's)
+
 LEAVES = ("embed", "unembed", "final_norm")
 LAYER_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_gate",
                 "w_up", "w_down")
+
+#: the CPU tests' size: Granite's layout with tiny widths
+SMOKE = {
+    "model": dict(hidden_size=64, num_attention_heads=4,
+                  num_key_value_heads=2, head_dim=16, intermediate_size=160,
+                  vocab_size=257),
+    # the smoke size's own limits, above what sound runs read on the CPU
+    # (six seeds: loss 4.3e-4, gradient 4.8e-3, change 1.8e-3) and below
+    # what the fp8 control read on three (1.3e-3, 2.7e-2, 3.8e-3)
+    "limits": dict(loss_gap=1e-3, grad_gap=1.2e-2, change_gap=3e-3),
+}
+
+
+def program_config(cfg: Mapping):
+    """The program's ``ModelConfig`` for a Granite configuration."""
+    from repro.models import ModelConfig
+    m, prec = cfg["model"], cfg["precision"]
+    return ModelConfig(
+        name=cfg["name"], family="dense",
+        num_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
+        num_heads=m["num_attention_heads"],
+        num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
+        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
+        rope_theta=m["rope_theta"], norm_eps=m["rms_norm_eps"],
+        tie_embeddings=m["tie_word_embeddings"],
+        param_dtype=prec["params"], compute_dtype=prec["compute"])
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,6 @@ sys.path[:0] = [str(Path(__file__).resolve().parents[2]),
                 str(Path(__file__).resolve().parents[2] / "src")]
 
 from bench import harness  # noqa: E402
-from bench.reference import granite as ref  # noqa: E402
 from bench.reference.dataplane import TokenGenerator  # noqa: E402
 from bench.tests.smoke import smoke_cell  # noqa: E402
 
@@ -26,8 +25,8 @@ def test_fp8_control_is_not_correct_and_the_program_is():
     t = cfg["train"]
     gen = TokenGenerator(SEED, cfg["model"]["vocab_size"], t["global_batch"],
                          t["seq_len"], cell.traffic["zipf_s"])
-    control = ref.reference_steps(cfg["model"], cfg["optimizer"], SEED,
-                                  [gen.grid(*i) for i in run.check_ids],
-                                  matmul="fp8")
+    control = harness.reference(cfg).reference_steps(
+        cfg["model"], cfg["optimizer"], SEED,
+        [gen.grid(*i) for i in run.check_ids], matmul="fp8")
     got = harness.compare_steps(control, run.ref, limits)
     assert any(v > lim for v, lim in got.values()), got

@@ -17,8 +17,10 @@ from bench import run as bench_run  # noqa: E402
 from bench.tests.faults import FAULTS  # noqa: E402
 from bench.tests.smoke import smoke_cell  # noqa: E402
 
-CELLS = {"granite8b-pretrain.steady": ("granite8b-pretrain", "steady"),
-         "granite8b-ckpt16.save-resume": ("granite8b-ckpt16", "save-resume")}
+ROOT = Path(__file__).resolve().parents[2]
+#: every cell of ``BENCHMARK.json``: name -> (config, traffic)
+CELLS = {w["name"]: (w["config"], w["traffic"]) for w in json.loads(
+    (ROOT / "BENCHMARK.json").read_text())["workloads"]}
 
 
 def run_main(monkeypatch, capsys, cell_name, hooks=None, trace=0):
