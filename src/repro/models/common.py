@@ -106,12 +106,62 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
-    """positions: (...,) int -> (cos, sin) of shape (..., head_dim//2)."""
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it:
+    ``rope_scaling`` of type ``yarn`` in its config.json."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * float(np.log(factor)) + 1.0
+
+
+def yarn_inv_freq(head_dim: int, theta: float, yarn: YarnScaling) -> np.ndarray:
+    """Per-pair inverse frequencies: the original ones for pairs that turn
+    more than ``beta_fast`` times over the original context, those divided by
+    ``factor`` for pairs that turn fewer than ``beta_slow`` times, and a
+    linear ramp between (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``)."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    extra = 1.0 / theta ** (np.arange(half, dtype=np.float64) * 2 / head_dim)
+    inter = extra / yarn.factor
+
+    def dim_of(rotations):
+        return head_dim * np.log(yarn.original_max_position
+                                 / (rotations * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    low = max(int(np.floor(dim_of(yarn.beta_fast))), 0)
+    high = min(int(np.ceil(dim_of(yarn.beta_slow))), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def rope_angles(positions: jax.Array, head_dim: int, theta: float,
+                yarn: Optional[YarnScaling] = None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """positions: (...,) int -> (cos, sin) of shape (..., head_dim//2).
+    With ``yarn``, YaRN's frequencies, and cos and sin scaled by
+    mscale(mscale) / mscale(mscale_all_dim), which is 1 where the two agree."""
+    half = head_dim // 2
+    if yarn is None:
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(head_dim, theta, yarn))
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) \
+            / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -162,15 +212,17 @@ def repeat_kv(x: jax.Array, n_heads: int) -> jax.Array:
 
 
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True,
-                    q_offset: int = 0) -> jax.Array:
-    """Reference attention; materializes (S, T) scores. Use for short seq."""
+                    causal: bool = True, q_offset: int = 0,
+                    scale: Optional[float] = None) -> jax.Array:
+    """Reference attention; materializes (S, T) scores. Use for short seq.
+    q, k: (B, S|T, H|G, dh); v: (B, T, G, dv), dv may differ from dh; the
+    scores are scaled by ``scale`` (default 1/sqrt(dh))."""
     B, S, H, dh = q.shape
     T = k.shape[1]
     kh = repeat_kv(k, H)
     vh = repeat_kv(v, H)
     scores = jnp.einsum("bshd,bthd->bhst", q, kh).astype(jnp.float32)
-    scores *= 1.0 / np.sqrt(dh)
+    scores *= 1.0 / np.sqrt(dh) if scale is None else scale
     if causal:
         qpos = jnp.arange(S)[:, None] + q_offset
         kpos = jnp.arange(T)[None, :]
@@ -183,7 +235,8 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       causal: bool = True, chunk: int = 1024,
-                      q_offset: int = 0) -> jax.Array:
+                      q_offset: int = 0,
+                      scale: Optional[float] = None) -> jax.Array:
     """Online-softmax attention scanning KV chunks: O(S * chunk) score memory.
 
     This is the TPU-native 'flash' adaptation expressible in pure XLA (the
@@ -191,7 +244,7 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     the default for long sequences so prefill_32k fits without S^2 temps.
     """
     B, S, H, dh = q.shape
-    T = k.shape[1]
+    T, dv = k.shape[1], v.shape[-1]
     kh = repeat_kv(k, H)
     vh = repeat_kv(v, H)
     if T % chunk:
@@ -200,9 +253,9 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kh = jnp.pad(kh, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, pad), (0, 0), (0, 0)))
     n_chunks = kh.shape[1] // chunk
-    qs = (q * (1.0 / np.sqrt(dh))).astype(q.dtype)
+    qs = (q * (1.0 / np.sqrt(dh) if scale is None else scale)).astype(q.dtype)
     kc = kh.reshape(B, n_chunks, chunk, H, dh).transpose(1, 0, 2, 3, 4)
-    vc = vh.reshape(B, n_chunks, chunk, H, dh).transpose(1, 0, 2, 3, 4)
+    vc = vh.reshape(B, n_chunks, chunk, H, dv).transpose(1, 0, 2, 3, 4)
     qpos = jnp.arange(S) + q_offset
 
     def body(carry, inputs):
@@ -224,22 +277,29 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     m0 = jnp.full((B, H, S), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, H, S), jnp.float32)
-    acc0 = jnp.zeros((B, H, S, dh), jnp.float32)
+    acc0 = jnp.zeros((B, H, S, dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, acc0), (kc, vc, jnp.arange(n_chunks)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def attention(q, k, v, causal=True, impl="auto", chunk=1024, q_offset=0):
+def attention(q, k, v, causal=True, impl="auto", chunk=1024, q_offset=0,
+              scale=None):
+    """Causal attention of q (B, S, H, dh) over k (B, T, G, dh) and v
+    (B, T, G, dv); ``scale`` multiplies the scores (default 1/sqrt(dh))."""
     if impl == "auto":
         impl = "chunked" if k.shape[1] > 4096 else "dense"
     if impl == "dense":
-        return dense_attention(q, k, v, causal=causal, q_offset=q_offset)
+        return dense_attention(q, k, v, causal=causal, q_offset=q_offset,
+                               scale=scale)
     if impl == "chunked":
         return chunked_attention(q, k, v, causal=causal, chunk=chunk,
-                                 q_offset=q_offset)
+                                 q_offset=q_offset, scale=scale)
     if impl == "pallas":
+        if scale is not None or v.shape[-1] != q.shape[-1]:
+            raise ValueError("the flash kernel takes neither a softmax "
+                             "scale nor a v width other than q's")
         from repro.kernels.flash_attention import ops as fa_ops
         return fa_ops.flash_attention(q, k, v, causal=causal)
     raise ValueError(f"unknown attention impl {impl!r}")

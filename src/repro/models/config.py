@@ -3,9 +3,11 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax.numpy as jnp
+
+from repro.models.common import YarnScaling
 
 
 @dataclass(frozen=True)
@@ -21,20 +23,27 @@ class ModelConfig:
     head_dim: Optional[int] = None
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    rope_yarn: Optional[YarnScaling] = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
 
+    # -- latent attention (MLA; on where kv_lora_rank > 0) ---------------------
+    # head_dim is the per-head q.k width without rope (qk_nope_head_dim)
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # -- MoE ----------------------------------------------------------------
-    moe_num_experts: int = 0
+    moe_num_experts: int = 0     # the router's width
     moe_top_k: int = 0
     moe_num_shared: int = 0      # shared (always-on) experts
     moe_d_ff: int = 0            # per-(routed-)expert hidden dim
-    moe_capacity_factor: float = 1.25
     moe_aux_coef: float = 0.01
-    # einsum = GShard baseline; scatter = Perf A1; local = Perf A2 (default:
-    # the measured-best expert-data-local dispatch; falls back to scatter
-    # without an active mesh)
-    moe_dispatch: str = "local"
+    moe_norm_topk: bool = True   # renormalise the top-k gates to sum to 1
+    # the experts held here, from expert 0 (0: all); choices of the other
+    # experts are another chip's part
+    moe_experts_held: int = 0
+    first_dense_layers: int = 0  # leading dense layers (d_ff) of an MoE stack
 
     # -- SSM / RWKV / hybrid ----------------------------------------------------
     ssm_state: int = 0
@@ -69,6 +78,14 @@ class ModelConfig:
                                self.d_model // max(1, self.num_heads))
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_experts_held or self.moe_num_experts
+
+    @property
     def pdtype(self):
         return jnp.dtype(self.param_dtype)
 
@@ -99,12 +116,13 @@ class ModelConfig:
         return int(sum(np.prod(l.shape) for l in leaves))
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: routed top-k + shared only)."""
+        """Params touched per token (MoE: shared experts and, of the routed
+        experts held, the top-k share a token visits on average)."""
         total = self.param_count()
         if self.family != "moe" or not self.moe_num_experts:
             return total
-        import numpy as np
-        # subtract inactive routed experts
         per_expert = 3 * self.d_model * self.moe_d_ff  # gate/up/down
-        inactive = (self.moe_num_experts - self.moe_top_k)
-        return int(total - self.num_layers * inactive * per_expert)
+        held = self.experts_held
+        inactive = held - self.moe_top_k * held / self.moe_num_experts
+        moe_layers = self.num_layers - self.first_dense_layers
+        return int(total - moe_layers * inactive * per_expert)

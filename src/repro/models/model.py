@@ -2,7 +2,8 @@
 
   param_specs(cfg)                         -> ParamSpec tree
   forward(cfg, params, batch)              -> (logits, aux)
-  loss_fn(cfg, params, batch)              -> (loss, metrics)
+  loss_fn(cfg, params, batch)              -> (loss, metrics); an expert
+                                              model's metrics add its counts
   decode_state_specs / decode_step         -> serving (KV cache or recurrent)
   prefill                                  -> attention families only
 
@@ -34,20 +35,27 @@ def param_specs(cfg: ModelConfig):
     raise ValueError(f"unknown family {cfg.family!r}")
 
 
-def forward(cfg: ModelConfig, params, batch: Dict) -> Tuple[jax.Array, jax.Array]:
+def _forward(cfg: ModelConfig, params, batch: Dict):
+    """(logits, aux, counts): ``counts`` as ``transformer.forward`` gives
+    them, empty for the families without experts."""
     tokens = batch["tokens"]
     fe = batch.get("frontend_embeds")
     if cfg.family in _ATTN_FAMILIES:
         return transformer.forward(cfg, params, tokens, frontend_embeds=fe)
     if cfg.family == "rwkv":
-        return rwkv6.forward(cfg, params, tokens)
+        return (*rwkv6.forward(cfg, params, tokens), {})
     if cfg.family == "hybrid":
-        return mamba2.forward(cfg, params, tokens)
+        return (*mamba2.forward(cfg, params, tokens), {})
     raise ValueError(cfg.family)
 
 
+def forward(cfg: ModelConfig, params, batch: Dict) -> Tuple[jax.Array, jax.Array]:
+    logits, aux, _ = _forward(cfg, params, batch)
+    return logits, aux
+
+
 def loss_fn(cfg: ModelConfig, params, batch: Dict) -> Tuple[jax.Array, Dict]:
-    logits, aux = forward(cfg, params, batch)
+    logits, aux, counts = _forward(cfg, params, batch)
     tokens = batch["tokens"]
     fe = batch.get("frontend_embeds")
     P = fe.shape[1] if fe is not None else 0
@@ -65,7 +73,7 @@ def loss_fn(cfg: ModelConfig, params, batch: Dict) -> Tuple[jax.Array, Dict]:
         labels = tokens[:, 1:]
         loss = softmax_cross_entropy(tok_logits, labels)
     total = loss + aux
-    return total, {"ce_loss": loss, "aux_loss": aux}
+    return total, {"ce_loss": loss, "aux_loss": aux, **counts}
 
 
 # ---------------------------------------------------------------------------

@@ -1,15 +1,36 @@
-"""Fine-grained Mixture-of-Experts FFN (DeepSeekMoE / Qwen3-MoE style).
+"""Fine-grained Mixture-of-Experts FFN (DeepSeekMoE, DeepSeek-V2, Qwen3-MoE).
 
-GShard-style capacity-based dispatch expressed as einsums so XLA-SPMD lowers it
-to all-to-all / all-gather over the expert-parallel ("model") mesh axis:
+One expert layer, told which experts it holds: ``E_held`` experts from
+``e0`` of the router's ``E`` (on one device, ``cfg.moe_experts_held`` from
+expert 0). Every token is routed over all ``E`` experts;
+only the choices of held experts are computed here. What the other experts
+add is the part of the chips that hold them (expert parallelism), and this
+layer stands in for none of it.
 
-  router -> top-k -> position-in-expert (cumsum) -> dispatch/combine one-hots
-  expert_in  = einsum('td,tec->ecd', x, dispatch)        # A2A to expert shards
-  expert_mid = swiglu over per-expert weights (E sharded)
-  y          = einsum('ecd,tec->td', expert_out, combine)
+  probs      = softmax(x . W_router)              (T, E), float32
+  gates, idx = top_k(probs, K)                    greedy; renormalised to sum
+                                                  to 1 iff cfg.moe_norm_topk
+  y = sum over the held choices (t, k) of gates[t, k] * expert_idx[t,k](x_t)
+      + shared(x)
+  expert_e(x) = W_down[e] . (silu(W_gate[e] . x) * (W_up[e] . x))
 
-Shared (always-on) experts are a plain dense SwiGLU added to the routed output.
-Aux load-balance loss follows Switch/DeepSeek: E * sum_e(f_e * p_e).
+No token is dropped at any imbalance. The choices of held experts are sorted
+by expert into a buffer that holds the worst case, T * min(K, E_held) rows
+(a token picks each expert at most once), and each of the gate, up and down
+projections is one grouped matmul (``jax.lax.ragged_dot``) over the held
+experts' groups of rows; the buffer's rows past the routed ones belong to no
+group. The shared experts are one dense SwiGLU of width
+``moe_num_shared * moe_d_ff``.
+
+Balance loss, DeepSeek-V2's per sequence (arXiv:2405.04434 §2.1.3):
+``moe_aux_coef * mean_b sum_e f_e * P_e`` with ``f_e = count_e * E / (S * K)``
+the sequence's share of choices of expert e, scaled so that uniform routing
+gives 1, and ``P_e`` the sequence's mean router probability of e.
+
+Under a mesh with a "model" axis each model shard holds ``E_held / |model|``
+of the experts and runs the same body with its own ``e0``; the partial
+outputs are summed over "model". Scopes for the trace: ``moe.route``,
+``moe.experts``, ``moe.shared``.
 """
 from __future__ import annotations
 
@@ -17,17 +38,17 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro.models.common import ParamSpec, swiglu, with_logical_constraint
+from repro.models.common import ParamSpec, swiglu
 from repro.models.config import ModelConfig
 from repro.sharding.specs import current_rules
 
 
 def moe_param_specs(cfg: ModelConfig, L: int) -> Dict[str, ParamSpec]:
-    D, E, F = cfg.d_model, cfg.moe_num_experts, cfg.moe_d_ff
+    D, E, F = cfg.d_model, cfg.experts_held, cfg.moe_d_ff
     specs = {
-        "router": ParamSpec((L, D, E), ("layers", "embed", None)),
+        "router": ParamSpec((L, D, cfg.moe_num_experts),
+                            ("layers", "embed", None)),
         "w_gate": ParamSpec((L, E, D, F), ("layers", "experts", "embed", "mlp")),
         "w_up": ParamSpec((L, E, D, F), ("layers", "experts", "embed", "mlp")),
         "w_down": ParamSpec((L, E, F, D), ("layers", "experts", "mlp", "embed")),
@@ -42,115 +63,103 @@ def moe_param_specs(cfg: ModelConfig, L: int) -> Dict[str, ParamSpec]:
     return specs
 
 
-def _routing(cfg: ModelConfig, p, xt: jax.Array):
-    """Router + top-k + position-in-expert (shared by both dispatch modes)."""
-    T = xt.shape[0]
+def route(cfg: ModelConfig, router: jax.Array, x: jax.Array):
+    """x (B, S, D) -> gates (B, S, K) float32, idx (B, S, K) int32 over the
+    router's E experts, and the balance loss."""
     E, K = cfg.moe_num_experts, cfg.moe_top_k
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                     # (T, E)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)               # (T, K)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)       # renormalize
-    cap = int(np.ceil(T * K / E * cfg.moe_capacity_factor))
-    cap = max(4, ((cap + 3) // 4) * 4)
-    # position-in-expert via cumulative counts across the K choices in order
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)     # (T, K, E)
-    flat = onehot.transpose(1, 0, 2).reshape(K * T, E)          # choice-major
-    pos = jnp.cumsum(flat, axis=0) - flat                       # (K*T, E)
-    pos = pos.reshape(K, T, E).transpose(1, 0, 2)               # (T, K, E)
-    pos_in_e = jnp.sum(pos * onehot, axis=-1).astype(jnp.int32)  # (T, K)
-    keep = pos_in_e < cap                                       # drop overflow
-    gate_vals = gate_vals * keep.astype(gate_vals.dtype)
-    return probs, onehot, gate_idx, gate_vals, pos_in_e, keep, cap
+    S = x.shape[1]
+    with jax.named_scope("moe.route"):
+        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                            router.astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, K)
+        if cfg.moe_norm_topk:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        counts = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32),
+                         axis=(1, 2))                            # (B, E)
+        f = counts * (E / (S * K))
+        aux = cfg.moe_aux_coef * jnp.mean(
+            jnp.sum(f * jnp.mean(probs, axis=1), axis=-1))
+    return gates, idx, aux
 
 
-def _expert_compute(cfg: ModelConfig, p, expert_in: jax.Array) -> jax.Array:
+def routed_experts(cfg: ModelConfig, x: jax.Array, gates: jax.Array,
+                   idx: jax.Array, wg: jax.Array, wu: jax.Array,
+                   wd: jax.Array, e0) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the routed output, dropping nothing.
+
+    x (T, D); gates, idx (T, K); wg, wu (E_held, D, F), wd (E_held, F, D)
+    hold experts [e0, e0 + E_held). Returns y (T, D) and the rows each held
+    expert computed, (E_held,) int32."""
     cd = cfg.cdtype
-    expert_in = with_logical_constraint(expert_in, ("experts", None, None))
-    g = jnp.einsum("ecd,edf->ecf", expert_in, p["w_gate"].astype(cd))
-    u = jnp.einsum("ecd,edf->ecf", expert_in, p["w_up"].astype(cd))
-    mid = swiglu(g, u)
-    out = jnp.einsum("ecf,efd->ecd", mid, p["w_down"].astype(cd))
-    return with_logical_constraint(out, ("experts", None, None))
+    T, D = x.shape
+    K = idx.shape[-1]
+    n = wg.shape[0]
+    R = T * min(K, n)
+    with jax.named_scope("moe.experts"):
+        local = idx.reshape(-1) - e0
+        held = (local >= 0) & (local < n)
+        eid = jnp.where(held, local, n)                          # (T*K,)
+        order = jnp.argsort(eid, stable=True)[:R]
+        rows = jnp.bincount(eid, length=n + 1)[:n].astype(jnp.int32)
+        routed = jnp.arange(R) < jnp.sum(rows)
+        # rows past the routed ones belong to no group: zero them so that
+        # nothing flows back into the tokens they were gathered from
+        xs = jnp.where(routed[:, None], x.astype(cd)[order // K], 0)
+        h = jax.lax.ragged_dot(xs, wg.astype(cd), rows)
+        u = jax.lax.ragged_dot(xs, wu.astype(cd), rows)
+        out = jax.lax.ragged_dot(swiglu(h, u), wd.astype(cd), rows)
+        # back to (token, choice) order; other experts' choices read zeros
+        slot = jnp.zeros((T * K,), jnp.int32).at[order].set(
+            jnp.arange(R, dtype=jnp.int32))
+        slot = jnp.where(held, slot, R)
+        out = jnp.concatenate([out, jnp.zeros((1, D), cd)], axis=0)
+        y = jnp.einsum("tkd,tk->td", out[slot].reshape(T, K, D),
+                       jnp.where(held.reshape(T, K), gates, 0).astype(cd))
+    return y, rows
 
 
-def _local_tokens_ffn(cfg: ModelConfig, xt, router, wg, wu, wd, e0: int,
-                      E_loc: int):
-    """Route LOCAL tokens through LOCAL experts [e0, e0+E_loc); returns the
-    partial output (remote-expert choices contribute zero here — their owning
-    model shard computes them, and the caller psums)."""
+def _shared(cfg: ModelConfig, p, x: jax.Array) -> jax.Array:
     cd = cfg.cdtype
-    T, D = xt.shape
-    E, K = cfg.moe_num_experts, cfg.moe_top_k
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                        router.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-    cap = int(np.ceil(T * K / E * cfg.moe_capacity_factor))
-    cap = max(4, ((cap + 3) // 4) * 4)
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)
-    flat = onehot.transpose(1, 0, 2).reshape(K * T, E)
-    pos = jnp.cumsum(flat, axis=0) - flat
-    pos_in_e = jnp.sum(pos.reshape(K, T, E).transpose(1, 0, 2) * onehot,
-                       axis=-1).astype(jnp.int32)
-    local = (gate_idx >= e0) & (gate_idx < e0 + E_loc)
-    keep = (pos_in_e < cap) & local
-    slot = (gate_idx - e0) * cap + pos_in_e
-    slot = jnp.where(keep, slot, E_loc * cap)
-    upd = jnp.broadcast_to(xt.astype(cd)[:, None, :], (T, K, D))
-    buf = jnp.zeros((E_loc * cap + 1, D), cd)
-    buf = buf.at[slot.reshape(-1)].add(upd.reshape(T * K, D), mode="drop")
-    expert_in = buf[:-1].reshape(E_loc, cap, D)
-    g = jnp.einsum("ecd,edf->ecf", expert_in, wg.astype(cd))
-    u = jnp.einsum("ecd,edf->ecf", expert_in, wu.astype(cd))
-    out = jnp.einsum("ecf,efd->ecd", swiglu(g, u), wd.astype(cd))
-    flat_out = jnp.concatenate(
-        [out.reshape(E_loc * cap, D), jnp.zeros((1, D), cd)], axis=0)
-    y_tk = flat_out[slot.reshape(-1)].reshape(T, K, D)
-    gates = (gate_vals * keep.astype(gate_vals.dtype)).astype(cd)
-    y = jnp.einsum("tkd,tk->td", y_tk, gates)
-    # aux load-balance terms from local tokens (identical across model shards)
-    frac = jnp.mean(jnp.sum(onehot, axis=1), axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac * mean_prob) * cfg.moe_aux_coef
-    return y, aux
+    with jax.named_scope("moe.shared"):
+        g = jnp.einsum("bsd,df->bsf", x, p["sh_gate"].astype(cd))
+        u = jnp.einsum("bsd,df->bsf", x, p["sh_up"].astype(cd))
+        return jnp.einsum("bsf,fd->bsd", swiglu(g, u),
+                          p["sh_down"].astype(cd))
 
 
-def _moe_ffn_local(cfg: ModelConfig, p, x: jax.Array):
-    """Expert-data-local dispatch (§Perf A2): every (data, model) shard routes
-    its LOCAL tokens through its LOCAL E/TP experts — tokens are replicated
-    across the model axis already, so dispatch needs NO communication; the only
+def _moe_ffn_mesh(cfg: ModelConfig, p, x: jax.Array):
+    """Expert-data-local dispatch: every (data, model) shard routes its
+    LOCAL tokens through its LOCAL experts — tokens are replicated across the
+    model axis already, so dispatch needs NO communication; the only
     collective is the partial-output psum over "model" (the same all-reduce a
     dense TP FFN pays). FSDP weight gathers happen explicitly inside the body.
     """
     rules = current_rules()
     mesh = rules.mesh
     dax = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    msize = mesh.shape["model"]
-    E = cfg.moe_num_experts
-    E_loc = E // msize
+    E_loc = cfg.experts_held // mesh.shape["model"]
     cd = cfg.cdtype
 
     def body(x_loc, router_l, wg_l, wu_l, wd_l):
-        # explicit FSDP gather of this layer's weights over the data axes
         gather = lambda w, ax: jax.lax.all_gather(
             w, dax, axis=ax, tiled=True) if dax else w
         router = gather(router_l, 0)
-        wg = gather(wg_l, 1)
-        wu = gather(wu_l, 1)
-        wd = gather(wd_l, 2)
+        wg, wu, wd = gather(wg_l, 1), gather(wu_l, 1), gather(wd_l, 2)
         e0 = jax.lax.axis_index("model") * E_loc
         B_loc, S, D = x_loc.shape
-        y, aux = _local_tokens_ffn(cfg, x_loc.reshape(B_loc * S, D), router,
-                                   wg, wu, wd, e0, E_loc)
+        gates, idx, aux = route(cfg, router, x_loc)
+        y, rows = routed_experts(cfg, x_loc.reshape(B_loc * S, D),
+                                 gates.reshape(B_loc * S, -1),
+                                 idx.reshape(B_loc * S, -1), wg, wu, wd, e0)
+        axes = dax + ("model",)
         y = jax.lax.psum(y, "model")
-        aux = jax.lax.pmean(aux, dax + ("model",)) if dax else \
-            jax.lax.pmean(aux, "model")
-        return y.reshape(B_loc, S, D), aux
+        aux = jax.lax.pmean(aux, axes)
+        counts = {"moe_rows_local": jax.lax.psum(jnp.sum(rows), axes),
+                  "moe_rows_max": jax.lax.pmax(jnp.max(rows), axes)}
+        return y.reshape(B_loc, S, D), aux, counts
 
+    P = jax.sharding.PartitionSpec
     in_specs = (
         rules.spec(("batch", None, None)),
         rules.spec(("embed", None)),            # router (D, E)
@@ -158,83 +167,31 @@ def _moe_ffn_local(cfg: ModelConfig, p, x: jax.Array):
         rules.spec(("experts", "embed", "mlp"), None),
         rules.spec(("experts", "mlp", "embed"), None),
     )
-    out_specs = (rules.spec(("batch", None, None)),
-                 jax.sharding.PartitionSpec())
+    out_specs = (rules.spec(("batch", None, None)), P(),
+                 {"moe_rows_local": P(), "moe_rows_max": P()})
     fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    y, aux = fn(x.astype(cd), p["router"], p["w_gate"], p["w_up"], p["w_down"])
-    if cfg.moe_num_shared:
-        xs = x.astype(cd)
-        sg = jnp.einsum("bsd,df->bsf", xs, p["sh_gate"].astype(cd))
-        su = jnp.einsum("bsd,df->bsf", xs, p["sh_up"].astype(cd))
-        y = y + jnp.einsum("bsf,fd->bsd", swiglu(sg, su),
-                           p["sh_down"].astype(cd))
-    return y.astype(x.dtype), aux
+    return fn(x.astype(cd), p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 
 def moe_ffn(cfg: ModelConfig, p: Dict[str, jax.Array],
-            x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (y, aux_loss). Router math in fp32.
-
-    Dispatch modes (cfg.moe_dispatch):
-      * "einsum"  — GShard-style dense dispatch/combine tensors (T, E, cap).
-        Baseline; costs O(T·E·cap·d) FLOPs/bytes, which DWARFS the useful
-        expert compute for fine-grained MoE (measured: useful ratio 0.006 for
-        deepseek-moe-16b).
-      * "scatter" — scatter-add tokens into the (E, cap, d) buffer at computed
-        (expert, slot) indices and gather back: O(T·k·d) data movement, zero
-        dispatch FLOPs. §Perf iteration A1.
-      * "local"   — expert-data-local shard_map routing (§Perf A2): zero
-        dispatch collectives; one psum("model") of the partial outputs.
-        Falls back to "scatter" without an active mesh.
-    """
-    if cfg.moe_dispatch == "local" and current_rules() is not None \
-            and "model" in current_rules().mesh.axis_names:
-        return _moe_ffn_local(cfg, p, x)
-    B, S, D = x.shape
-    E, K = cfg.moe_num_experts, cfg.moe_top_k
-    T = B * S
-    xt = x.reshape(T, D)
-    probs, onehot, gate_idx, gate_vals, pos_in_e, keep, cap = \
-        _routing(cfg, p, xt)
-    cd = cfg.cdtype
-
-    if cfg.moe_dispatch in ("scatter", "local"):  # "local" falls back here w/o mesh
-        slot = gate_idx * cap + pos_in_e                         # (T, K)
-        slot = jnp.where(keep, slot, E * cap)                    # drop bucket
-        upd = jnp.broadcast_to(xt.astype(cd)[:, None, :], (T, K, D))
-        buf = jnp.zeros((E * cap + 1, D), cd)
-        buf = buf.at[slot.reshape(-1)].add(
-            upd.reshape(T * K, D), mode="drop",
-            unique_indices=False, indices_are_sorted=False)
-        expert_in = buf[:-1].reshape(E, cap, D)
-        out = _expert_compute(cfg, p, expert_in)
-        flat_out = jnp.concatenate(
-            [out.reshape(E * cap, D), jnp.zeros((1, D), cd)], axis=0)
-        y_tk = flat_out[slot.reshape(-1)].reshape(T, K, D)       # gather back
-        y = jnp.einsum("tkd,tk->td", y_tk,
-                       gate_vals.astype(cd)).reshape(B, S, D)
+            x: jax.Array) -> Tuple[jax.Array, jax.Array, Dict]:
+    """x: (B, S, D) -> (y, aux_loss, counts). ``counts``: the choices this
+    layer computed here (``moe_rows_local``) and the fullest held expert's
+    rows (``moe_rows_max``)."""
+    rules = current_rules()
+    if rules is not None and "model" in rules.mesh.axis_names:
+        y, aux, counts = _moe_ffn_mesh(cfg, p, x)
     else:
-        pos_oh = jax.nn.one_hot(pos_in_e, cap, dtype=jnp.float32)  # (T, K, cap)
-        dispatch = jnp.einsum(
-            "tke,tkc->tec", onehot * keep[..., None].astype(jnp.float32),
-            pos_oh)
-        combine = jnp.einsum("tke,tkc->tec",
-                             onehot * gate_vals[..., None], pos_oh)
-        expert_in = jnp.einsum("td,tec->ecd", xt.astype(cd),
-                               dispatch.astype(cd))
-        out = _expert_compute(cfg, p, expert_in)
-        y = jnp.einsum("ecd,tec->td", out, combine.astype(cd)).reshape(B, S, D)
-
-    # shared experts (dense path)
+        B, S, D = x.shape
+        gates, idx, aux = route(cfg, p["router"], x)
+        y, rows = routed_experts(cfg, x.reshape(B * S, D),
+                                 gates.reshape(B * S, -1),
+                                 idx.reshape(B * S, -1), p["w_gate"],
+                                 p["w_up"], p["w_down"], 0)
+        y = y.reshape(B, S, D)
+        counts = {"moe_rows_local": jnp.sum(rows),
+                  "moe_rows_max": jnp.max(rows)}
     if cfg.moe_num_shared:
-        xs = x.astype(cd)
-        sg = jnp.einsum("bsd,df->bsf", xs, p["sh_gate"].astype(cd))
-        su = jnp.einsum("bsd,df->bsf", xs, p["sh_up"].astype(cd))
-        y = y + jnp.einsum("bsf,fd->bsd", swiglu(sg, su), p["sh_down"].astype(cd))
-
-    # aux load-balance loss: E * sum_e(mean_t route_frac_e * mean_t prob_e)
-    frac = jnp.mean(jnp.sum(onehot, axis=1), axis=0)            # (E,)
-    mean_prob = jnp.mean(probs, axis=0)                         # (E,)
-    aux = E * jnp.sum(frac * mean_prob) * cfg.moe_aux_coef
-    return y.astype(x.dtype), aux
+        y = y + _shared(cfg, p, x.astype(cfg.cdtype))
+    return y.astype(x.dtype), aux, counts

@@ -105,6 +105,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -153,6 +156,11 @@ class _LiveSpan:
         self.t0 = time.time_ns()
         self._p0 = time.perf_counter_ns()
         return self
+
+    def note(self, **args) -> None:
+        """Add arguments known only inside the span, such as what it
+        fetched; they go to the recorded span, not to the profiler's."""
+        self.args = {**(self.args or {}), **args}
 
     def __exit__(self, exc_type, exc, tb):
         dur = (time.perf_counter_ns() - self._p0) / 1e9

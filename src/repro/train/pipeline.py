@@ -72,7 +72,16 @@ class PipelineStats(StatsView):
         "data_wait_s": GAUGE,       # cumulative critical-path stall seconds
         "h2d_s": GAUGE,             # cumulative critical-path h2d seconds
         "compute_s": GAUGE,         # cumulative step-fn seconds
+        # an expert model's step: choices its held experts computed, and
+        # the last step's fullest held expert (rows)
+        "moe_rows_local": COUNTER,
+        "moe_rows_max": GAUGE,
     }
+
+
+#: the step metrics an expert model adds, recorded as ``pipeline.sync``'s
+#: arguments and in ``PipelineStats``
+MOE_COUNTS = ("moe_rows_local", "moe_rows_max")
 
 
 # ---------------------------------------------------------------------------
@@ -542,6 +551,7 @@ class FusedTrainLoop:
         use the loop as a context manager; ``run`` may be called repeatedly
         — state (params, opt, cursor position) carries across calls.
         """
+        import jax
         self.start()
         timings: List[StepTiming] = []
         tokens_total = 0
@@ -557,8 +567,15 @@ class FusedTrainLoop:
                     self.params, self.opt_state, metrics = self.step_fn(
                         self.params, self.opt_state,
                         {"tokens": entry.device_tokens})
-                with trace_span("pipeline.sync", cat="compute", step=step):
-                    loss = float(metrics["loss"])   # forces device sync
+                with trace_span("pipeline.sync", cat="compute",
+                                step=step) as sync:
+                    # one fetch of every metric: forces the device sync
+                    metrics = jax.device_get(metrics)
+                    loss = float(metrics["loss"])
+                    counts = {k: int(metrics[k]) for k in MOE_COUNTS
+                              if k in metrics}
+                    if counts:
+                        sync.note(**counts)
                 compute_s = time.perf_counter() - tc
             if on_batch is not None:
                 on_batch(self.consumed, entry.host_tokens)
@@ -572,6 +589,9 @@ class FusedTrainLoop:
             self.stats.data_wait_s += data_wait_s
             self.stats.h2d_s += h2d_s
             self.stats.compute_s += compute_s
+            if counts:
+                self.stats.moe_rows_local += counts["moe_rows_local"]
+                self.stats.moe_rows_max = float(counts["moe_rows_max"])
         return FusedReport(steps=num_steps, tokens=tokens_total,
                            wall_s=time.perf_counter() - t_run0,
                            timings=timings)

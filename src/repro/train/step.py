@@ -6,7 +6,9 @@ decode — plus the abstract input specs used by the multi-pod dry-run.
   * batch (GB, S) is reshaped to (n_micro, GB/n_micro, S) and scanned, grads
     accumulated in fp32 — per-microbatch activation memory is what remat +
     microbatching bound on a 16 GB chip;
-  * the AdamW update runs once on the accumulated grads.
+  * the AdamW update runs once on the accumulated grads;
+  * an expert model's counts (``COUNTS``) ride in the metrics, summed or
+    maxed over the microbatches.
 """
 from __future__ import annotations
 
@@ -21,6 +23,11 @@ from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.train.optimizer import (OptimizerConfig, adamw_update,
                                    init_opt_state)
+
+
+#: the model's counts that the step's metrics carry, each folded over the
+#: microbatches: the choices computed here, and the fullest held expert
+COUNTS = {"moe_rows_local": jnp.add, "moe_rows_max": jnp.maximum}
 
 
 @dataclass(frozen=True)
@@ -108,29 +115,35 @@ def make_train_step(cfg: ModelConfig, opt_cfg: OptimizerConfig,
             lambda p: jnp.zeros(p.shape, acc_dt), params))
 
         def micro_body(acc, mb):
-            g_acc, loss_acc, aux_acc = acc
+            g_acc, loss_acc, aux_acc, counts_acc = acc
             (loss, metrics), grads = grad_fn(params, mb)
             grads = _constrain_grads(grads)
             g_acc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(acc_dt), g_acc, grads)
             g_acc = _constrain_grads(g_acc)
-            return (g_acc, loss_acc + loss, aux_acc + metrics["aux_loss"]), None
+            counts = {k: COUNTS[k](counts_acc[k], metrics[k])
+                      for k in counts_acc}
+            return (g_acc, loss_acc + loss, aux_acc + metrics["aux_loss"],
+                    counts), None
 
         if n_micro > 1:
-            (grads, loss_sum, aux_sum), _ = jax.lax.scan(
-                micro_body, (zero_grads, 0.0, 0.0), micro)
+            counts0 = {k: jnp.zeros((), jnp.int32) for k in COUNTS} \
+                if cfg.family == "moe" else {}
+            (grads, loss_sum, aux_sum, counts), _ = jax.lax.scan(
+                micro_body, (zero_grads, 0.0, 0.0, counts0), micro)
         else:
             mb0 = {k: v[0] for k, v in micro.items()}
             (loss, metrics), grads = grad_fn(params, mb0)
             grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32),
                                            grads)
             loss_sum, aux_sum = loss, metrics["aux_loss"]
+            counts = {k: metrics[k] for k in COUNTS if k in metrics}
 
         grads = jax.tree_util.tree_map(lambda g: g / n_micro, grads)
         new_params, new_opt, om = adamw_update(opt_cfg, params, grads,
                                                opt_state)
         metrics = {"loss": loss_sum / n_micro, "aux_loss": aux_sum / n_micro,
-                   **om}
+                   **om, **counts}
         return new_params, new_opt, metrics
 
     return train_step

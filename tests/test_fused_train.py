@@ -232,6 +232,44 @@ def test_each_step_splits_into_dispatch_and_sync(tiny_step):
     assert after[:2] == [2, 3]
 
 
+def test_an_expert_models_counts_ride_the_sync(tiny_step):
+    """The one sync per step fetches the whole metrics dict: an expert
+    model's row counts become args of ``pipeline.sync`` and loop stats;
+    Granite's sync carries its step alone."""
+    moe = get_smoke_config("deepseek_moe_16b")
+    moe_step = jax.jit(make_train_step(moe, OptimizerConfig(), StepConfig(
+        microbatches=2)))
+    moe_params = init_params(param_specs(moe), seed=0)
+    cases = [(tiny_step[0], tiny_step[1], tiny_step[2], tiny_step[3]),
+             (moe, moe_step, moe_params, init_opt_state(moe_params))]
+    got = []
+    for cfg, step_fn, params, opt in cases:
+        sess = TrainSession(MemoryObjectStore(), TOPO,
+                            namespace=f"runs/counts_{cfg.family}")
+        _produce(sess, 4, cfg.vocab_size)
+        tracer = enable_tracing()
+        tracer.clear()
+        try:
+            with FusedTrainLoop(_fan_in(sess), step_fn, params, opt,
+                                topology=TOPO, depth=2,
+                                timeout_s=30.0) as loop:
+                loop.run(2)
+        finally:
+            disable_tracing()
+        sess.close()
+        got.append(([s.args for s in tracer.spans()
+                     if s.name == "pipeline.sync"], loop.stats))
+        tracer.clear()
+    (dense_args, dense_stats), (moe_args, moe_stats) = got
+    assert dense_args == [{"step": 0}, {"step": 1}]
+    assert dense_stats.moe_rows_local == 0
+    # 4 x 32 tokens a step, top 2 of 8 experts, all held, one expert layer
+    assert [a["moe_rows_local"] for a in moe_args] == [256, 256]
+    assert all(32 <= a["moe_rows_max"] <= 64 for a in moe_args)
+    assert moe_stats.moe_rows_local == 512
+    assert moe_stats.moe_rows_max == moe_args[-1]["moe_rows_max"]
+
+
 def test_throttled_store_shifts_split_toward_data_wait(tiny_step):
     cfg, step_fn, params, opt = tiny_step
 

@@ -604,3 +604,19 @@ def test_live_producer_consumer_snapshots(ns, reg):
     assert rows["producer.p0"]["metrics"]["tgbs_written"] == 4
     assert rows["consumer.d0c0"]["metrics"]["steps_consumed"] == 3
     assert rows["consumer.d0c0"]["lag_steps"] == 1
+
+
+def test_a_span_notes_arguments_learnt_inside_it():
+    tracer = enable_tracing()
+    tracer.clear()
+    try:
+        with trace_span("pipeline.sync", cat="compute", step=3) as sp:
+            sp.note(moe_rows_local=12, moe_rows_max=5)
+    finally:
+        disable_tracing()
+    (span,) = [s for s in tracer.spans() if s.name == "pipeline.sync"]
+    tracer.clear()
+    assert span.args == {"step": 3, "moe_rows_local": 12, "moe_rows_max": 5}
+    with trace_span("pipeline.sync", step=4) as sp:   # disabled: a no-op
+        sp.note(moe_rows_local=1)
+    assert tracer.spans() == []
